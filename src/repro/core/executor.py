@@ -509,6 +509,7 @@ class PipelineExecutor:
                 )
 
             def f_branch(c):
+                @jax.named_scope("F")
                 def body(state, t):
                     side_mb = side_at(t["mb"])
                     x_inbox = from_chan(_dyn_get(state["act_in"][c], t["in_slot"]))
@@ -526,7 +527,8 @@ class PipelineExecutor:
                     )
 
                     def with_loss(st):
-                        loss, sres = prog.sink.fwd(shared, y, side_mb)
+                        with jax.named_scope("head_ce"):
+                            loss, sres = prog.sink.fwd(shared, y, side_mb)
                         st = dict(st)
                         st["sink"] = jax.tree_util.tree_map(
                             lambda b, v: _masked_set(b, t["sink_slot"], v, True),
@@ -544,6 +546,7 @@ class PipelineExecutor:
                 return body
 
             def b_branch(c):
+                @jax.named_scope("B")
                 def body(state, t):
                     side_mb = side_at(t["mb"])
                     res = pool_get(state["res"], share_res, c, t["res_slot"])
@@ -556,9 +559,10 @@ class PipelineExecutor:
                         def from_sink(_):
                             sres = _tree_dyn_get(state["sink"], t["sink_slot"])
                             ones = jnp.ones(loss_sh.shape, loss_sh.dtype)
-                            dy_s, swctx = prog.sink.bwd_x(
-                                shared, sres, ones, side_mb
-                            )
+                            with jax.named_scope("head_ce"):
+                                dy_s, swctx = prog.sink.bwd_x(
+                                    shared, sres, ones, side_mb
+                                )
                             return dy_s.astype(prog.act_dtype), swctx
 
                         def from_inbox(_):
@@ -611,6 +615,7 @@ class PipelineExecutor:
                 return body
 
             def w_branch(c):
+                @jax.named_scope("W")
                 def body(state, t):
                     side_mb = side_at(t["mb"])
                     # W consumes only the M_W context -- no residuals, no
@@ -635,7 +640,8 @@ class PipelineExecutor:
                             swctx = _tree_dyn_get(
                                 st["sink_wctx"], t["sink_wctx_slot"]
                             )
-                            sg = prog.sink.bwd_w(shared, swctx, side_mb)
+                            with jax.named_scope("head_ce"):
+                                sg = prog.sink.bwd_w(shared, swctx, side_mb)
                             st = dict(st)
                             st["shared_acc"] = jax.tree_util.tree_map(
                                 lambda a, b: a + b.astype(a.dtype),
@@ -696,7 +702,8 @@ class PipelineExecutor:
                     shift = _CHANNEL_SHIFT[d]
                     p = plan.p
                     perm = [(i, (i + shift) % p) for i in range(p)]
-                    got = jax.lax.ppermute(payload, self.pipe_axis, perm)
+                    with jax.named_scope("send"):
+                        got = jax.lax.ppermute(payload, self.pipe_axis, perm)
                     is_act_chan = d in (CHANNEL_FWD_UP, CHANNEL_FWD_DOWN)
                     valid = t["recv_valid"][d]
                     ridx = t["recv_chunk"][d] * (
@@ -746,8 +753,9 @@ class PipelineExecutor:
                 )
 
             grads = state["grad_acc"]
-            shared_grads = jax.lax.psum(state["shared_acc"], self.pipe_axis)
-            loss = jax.lax.psum(state["loss"], self.pipe_axis)
+            with jax.named_scope("opt"), jax.named_scope("sync"):
+                shared_grads = jax.lax.psum(state["shared_acc"], self.pipe_axis)
+                loss = jax.lax.psum(state["loss"], self.pipe_axis)
             return grads, shared_grads, loss
 
         return grad_fn
@@ -920,7 +928,8 @@ class PipelineExecutor:
                 shift = _CHANNEL_SHIFT[d]
                 senders = np.nonzero(col["send_channel"] == d)[0]
                 edges = [(int(s), int((s + shift) % p)) for s in senders]
-                got = jax.lax.ppermute(send_val, self.pipe_axis, edges)
+                with jax.named_scope("send"):
+                    got = jax.lax.ppermute(send_val, self.pipe_axis, edges)
                 valid = col["recv_valid"][:, d]
                 is_act_chan = d in (CHANNEL_FWD_UP, CHANNEL_FWD_DOWN)
                 stride = S_act if is_act_chan else S_grad

@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from jax.extend import core as _jcore
+from jax.extend import source_info_util as _src_info
 
 _Var = _jcore.Var
 _Literal = _jcore.Literal
@@ -155,6 +156,9 @@ def _avals_key(*trees):
 
 
 def _eval_eqns(jaxpr, eqn_ids, env, skip=()):
+    """Bind ``jaxpr.eqns[eqn_ids]`` in order, each under its own name stack
+    stacked on the caller's (as ``jax.core.eval_jaxpr`` does), so the scopes
+    set inside the model (``attn``) stay on the B and W ops."""
     for i in eqn_ids:
         if i in skip:
             continue
@@ -162,11 +166,16 @@ def _eval_eqns(jaxpr, eqn_ids, env, skip=()):
         invals = [
             v.val if isinstance(v, _Literal) else env[v] for v in eqn.invars
         ]
-        if isinstance(eqn, _SynthScanEqn):
-            outs = eqn.run(invals)
-        else:
-            ans = eqn.primitive.bind(*invals, **eqn.params)
-            outs = ans if eqn.primitive.multiple_results else [ans]
+        info = eqn.source_info
+        with _src_info.user_context(
+            info.traceback,
+            name_stack=_src_info.current_name_stack() + info.name_stack,
+        ):
+            if isinstance(eqn, _SynthScanEqn):
+                outs = eqn.run(invals)
+            else:
+                ans = eqn.primitive.bind(*invals, **eqn.params)
+                outs = ans if eqn.primitive.multiple_results else [ans]
         for var, val in zip(eqn.outvars, outs):
             if not isinstance(var, _DropVar):
                 env[var] = val
@@ -314,6 +323,8 @@ class _SynthScanEqn:
         self.outvars = list(outvars)
         self.params: Dict[str, Any] = {}
         self.run = run
+        # set to the split scan's own by _split_scans
+        self.source_info = _src_info.new_source_info()
 
 
 def _eqn_replace(eqn, invars=None, outvars=None):
@@ -1020,11 +1031,10 @@ def _split_scans(ir: _FlatIR, need_dx, need_dp) -> bool:
         if halves is None:
             new_eqns.append(eqn)
             continue
-        b_eqn, w_eqn = halves
-        if b_eqn is not None:
-            new_eqns.append(b_eqn)
-        if w_eqn is not None:
-            new_eqns.append(w_eqn)
+        for half in halves:
+            if half is not None:
+                half.source_info = eqn.source_info
+                new_eqns.append(half)
         changed = True
     if changed:
         ir.eqns = new_eqns

@@ -107,7 +107,13 @@ def build_train_step(
             v=tuple(unstack(x) for x in opt_state.v),
         )
         grads, shared_grads, loss = grad_fn(local, shared, side)
+        return update(local, shared, opt_state, shared_opt, grads, shared_grads, loss)
 
+    @jax.named_scope("opt")
+    def update(local, shared, opt_state, shared_opt, grads, shared_grads, loss):
+        """The optimizer tail: dp reduction, freezing, gradient statistics,
+        AdamW and post-validation.  Its reductions over the pipe sit under
+        ``sync`` as well, where a stage waits for the slowest one."""
         if binding.dp is not None:
             if tcfg.grad_compress != "none":
                 from ..optim.compress import compressed_psum
@@ -153,19 +159,21 @@ def build_train_step(
 
         if tcfg.postval_mode == "sync":
             # baseline: blocking global reduction before the step
-            g_stats = postval.GradStats(
-                jax.lax.psum(stats.sumsq, binding.pipe),
-                jax.lax.psum(
-                    stats.nonfinite.astype(jnp.float32), binding.pipe
+            with jax.named_scope("sync"):
+                g_stats = postval.GradStats(
+                    jax.lax.psum(stats.sumsq, binding.pipe),
+                    jax.lax.psum(
+                        stats.nonfinite.astype(jnp.float32), binding.pipe
+                    )
+                    > 0.5,
                 )
-                > 0.5,
-            )
             new_params, new_state = postval.sync_step(
                 both_params, state, both_grads, acfg, g_stats
             )
             amended = jnp.zeros((), bool)
         else:
-            partial_s, full_s = postval.pipe_prefix_stats(stats, binding.pipe)
+            with jax.named_scope("sync"):
+                partial_s, full_s = postval.pipe_prefix_stats(stats, binding.pipe)
             p1, s1, dec = postval.optimistic_step(
                 both_params, state, both_grads, partial_s, acfg
             )
@@ -188,13 +196,9 @@ def build_train_step(
         new_stacked = tuple(
             jax.tree_util.tree_map(lambda a: a[None], sp) for sp in new_local
         )
-        metrics = {
-            "loss": loss,
-            "grad_norm": jnp.sqrt(
-                jax.lax.psum(stats.sumsq, binding.pipe)
-            ),
-            "amended": amended,
-        }
+        with jax.named_scope("sync"):
+            grad_norm = jnp.sqrt(jax.lax.psum(stats.sumsq, binding.pipe))
+        metrics = {"loss": loss, "grad_norm": grad_norm, "amended": amended}
         return new_stacked, new_shared, new_opt, new_shared_opt, metrics
 
     stacked_sdt, shared_sdt = _abstract_params(cfg, spec, placement)

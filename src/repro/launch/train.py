@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs import get_config, get_reduced
@@ -132,8 +133,15 @@ class StepSetup:
     data: SyntheticLM
 
     def side_at(self, step_idx: int):
-        side = side_from_batch(self.data.batch_at(step_idx), self.spec, cfg=self.cfg)
-        return jax.device_put(side, self.side_sharding)
+        """The step's side inputs on the mesh.  Its spans (``repro.feed.*``)
+        show in a profiler trace, on the clock of the device ops."""
+        with TraceAnnotation("repro.feed"):
+            with TraceAnnotation("repro.feed.batch"):
+                batch = self.data.batch_at(step_idx)
+            with TraceAnnotation("repro.feed.build"):
+                side = side_from_batch(batch, self.spec, cfg=self.cfg)
+            with TraceAnnotation("repro.feed.put"):
+                return jax.device_put(side, self.side_sharding)
 
     def init_state(self, seed: int = 0):
         """Params + AdamW moments, created on the mesh already laid out as

@@ -205,6 +205,7 @@ def _match_kv_heads(q_heads_local, k, v, cfg, ctx: ShardCtx):
     return jnp.take(k, sel, axis=2), jnp.take(v, sel, axis=2)
 
 
+@jax.named_scope("attn")
 def attention(q, k, v, *, causal=True, window=None, softcap=None, block=1024):
     if q.shape[1] <= 2 * block:
         return _attend_dense(q, k, v, causal, window, softcap)
