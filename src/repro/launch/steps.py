@@ -82,7 +82,7 @@ def build_train_step(
       (stacked_params, shared, opt_state, shared_opt, metrics)
     """
     tcfg = tcfg or TrainStepConfig()
-    program = build_program(cfg, spec, placement)
+    program = build_program(cfg, spec, placement, platform=mesh.devices.flat[0].platform)
     execu = PipelineExecutor(
         program,
         plan,

@@ -350,12 +350,15 @@ def build_program(
     spec: RunSpec,
     placement,
     compact: Optional[bool] = None,
+    platform: Optional[str] = None,
 ) -> PipelineProgram:
     """``compact`` selects the byte-minimal W-context split (core/passes);
     the default follows ``auto_fbw``'s global default.  ``compact=False``
     is the whole-scan-in-B / frontier-cut baseline the measured-memory
-    tests compare against."""
-    ctx = ShardCtx(tp_axis=spec.tp_axis, tp_size=spec.tp_size)
+    tests compare against.  ``platform`` is that of the devices the step
+    runs on (None: the process's default backend); it picks the attention
+    kernel (``modules.attention``)."""
+    ctx = ShardCtx(tp_axis=spec.tp_axis, tp_size=spec.tp_size, platform=platform)
     src_fwd, src_bwd_w = make_src(cfg, ctx)
     sink_fn = make_sink_fn(cfg, ctx, spec.m)
 

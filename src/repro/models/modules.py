@@ -34,6 +34,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..kernels import attention as flash
+
 PyTree = Any
 
 __all__ = [
@@ -56,6 +58,7 @@ class ShardCtx:
 
     tp_axis: Optional[str] = None  # mesh axis name, None = no TP
     tp_size: int = 1
+    platform: Optional[str] = None  # the step's devices; None = default backend
 
     def psum(self, x):
         if self.tp_axis is None:
@@ -141,8 +144,10 @@ def _softcap(x, cap):
 
 
 # --------------------------------------------------------------------- #
-# attention (dense for short sequences, q-block-chunked + remat for long
-# sequences so activation memory stays O(s * d) per layer)
+# attention: causal self-attention on the TPU through the blocked kernel
+# (kernels/attention.py) where it takes the input; otherwise dense for short
+# sequences, q-block-chunked + remat for long sequences so activation memory
+# stays O(s * d) per layer
 # --------------------------------------------------------------------- #
 def _attend_dense(q, k, v, causal, window, softcap, q_offset=0):
     """q: (b, sq, hq, d); k/v: (b, sk, hq, d) head-matched -> (b, sq, hq, d)."""
@@ -187,26 +192,45 @@ def _attend_chunked(q, k, v, causal, window, softcap, block=1024):
     return out[:, :s]
 
 
-def _match_kv_heads(q_heads_local, k, v, cfg, ctx: ShardCtx):
-    """Expand/select kv heads so k/v carry one head per local q head."""
-    hq, hk, tp = cfg["n_heads"], cfg["n_kv_heads"], _tp(cfg)
-    group = hq // hk
-    if _kv_sharded(cfg) or tp == 1:
-        rep = q_heads_local // k.shape[2]
-        if rep > 1:
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        return k, v
-    # q sharded, kv replicated: local q head i -> global q head
-    # r*hq_l + i -> kv head (r*hq_l + i) // group
-    r = ctx.index()
-    gq = r * q_heads_local + jnp.arange(q_heads_local)
+def _repeat_kv_heads(q_heads, k, v):
+    """Repeat grouped kv heads so k/v carry one head per q head."""
+    rep = q_heads // k.shape[2]
+    if rep > 1:
+        k = jnp.repeat(k, rep, axis=2)
+        v = jnp.repeat(v, rep, axis=2)
+    return k, v
+
+
+def _select_kv_heads(q_heads_local, k, v, cfg, ctx: ShardCtx):
+    """q sharded, kv replicated: local q head i -> global q head
+    r*hq_l + i -> kv head (r*hq_l + i) // group, one kv head per local q head."""
+    group = cfg["n_heads"] // cfg["n_kv_heads"]
+    gq = ctx.index() * q_heads_local + jnp.arange(q_heads_local)
     sel = gq // group
     return jnp.take(k, sel, axis=2), jnp.take(v, sel, axis=2)
 
 
+def _match_kv_heads(q_heads_local, k, v, cfg, ctx: ShardCtx):
+    """Expand/select kv heads so k/v carry one head per local q head."""
+    if _kv_sharded(cfg) or _tp(cfg) == 1:
+        return _repeat_kv_heads(q_heads_local, k, v)
+    return _select_kv_heads(q_heads_local, k, v, cfg, ctx)
+
+
 @jax.named_scope("attn")
-def attention(q, k, v, *, causal=True, window=None, softcap=None, block=1024):
+def attention(q, k, v, *, causal=True, window=None, softcap=None, block=1024,
+              platform=None):
+    """q: (b, sq, hq, d); k/v: (b, sk, hk, d), q head i reading kv head
+    i // (hq // hk).  ``platform`` is that of the step's devices (None: the
+    process's default backend); where the blocked kernel takes the input
+    there, it runs, else the jnp path below."""
+    kb = flash.kernel_block(
+        q.shape, k.shape, v.shape, causal=causal, window=window,
+        softcap=softcap, platform=platform or jax.default_backend(),
+    )
+    if kb is not None:
+        return flash.causal_attention(q, k, v, kb)
+    k, v = _repeat_kv_heads(q.shape[2], k, v)
     if q.shape[1] <= 2 * block:
         return _attend_dense(q, k, v, causal, window, softcap)
     return _attend_chunked(q, k, v, causal, window, softcap, block)
@@ -247,9 +271,11 @@ def apply_attn(p, x, positions, cfg, ctx: ShardCtx, *, window=None):
     k = (xin @ wk).reshape(b, s, hk_l, dh)
     v = (xin @ wv).reshape(b, s, hk_l, dh)
     q, k = rope(q, positions), rope(k, positions)
-    k, v = _match_kv_heads(hq_l, k, v, cfg, ctx)
+    if not (_kv_sharded(cfg) or tp == 1):
+        k, v = _select_kv_heads(hq_l, k, v, cfg, ctx)
     o = attention(
-        q, k, v, causal=True, window=window, softcap=cfg.get("attn_softcap")
+        q, k, v, causal=True, window=window, softcap=cfg.get("attn_softcap"),
+        platform=ctx.platform,
     )
     o = o.reshape(b, s, hq_l * dh) @ wo
     return x + (ctx.psum(o) if qs and tp > 1 else o)
@@ -319,7 +345,7 @@ def apply_mla(p, x, positions, cfg, ctx: ShardCtx):
     k = jnp.concatenate(
         [k_nope, jnp.broadcast_to(k_rope, (b, s, hq, d_rope))], axis=-1
     )
-    o = attention(q, k, v, causal=True)
+    o = attention(q, k, v, causal=True, platform=ctx.platform)
     o = o.reshape(b, s, hq * dh) @ p["wo"]
     return x + (ctx.psum(o) if tp > 1 else o)
 

@@ -135,3 +135,17 @@ def _isolated_plan_cache(tmp_path_factory):
         os.environ.pop("REPRO_PLAN_CACHE_DIR", None)
     else:
         os.environ["REPRO_PLAN_CACHE_DIR"] = prev
+
+
+@pytest.fixture
+def flash_on_cpu(monkeypatch):
+    """Sends causal attention through the blocked kernel
+    (``repro.kernels.attention``) on the CPU, as ``attention()`` does on the
+    TPU, with the kernel run by the Pallas interpreter."""
+    from repro.kernels import attention as flash
+
+    monkeypatch.setattr(flash, "PLATFORM", "cpu")
+    monkeypatch.setattr(
+        flash, "causal_attention",
+        functools.partial(flash.causal_attention, interpret=True),
+    )

@@ -67,6 +67,35 @@ def test_step_ops_carry_scopes(mode, seq):
     assert any("/transpose(" in n for n in w_ops), w_ops[:5]
 
 
+def test_attention_kernel_ops_are_under_attn_flash(flash_on_cpu):
+    """With causal attention on the blocked kernel (as on the TPU; here the
+    Pallas interpreter), the kernel's forward and its backward ops lie under
+    ``attn/flash`` inside F and B: ``attn_ms`` keeps counting the backward,
+    and the ops under ``attn/flash`` are the kernel's share of it."""
+    import dataclasses
+
+    cfg = dataclasses.replace(
+        get_reduced("internlm2_1_8b"), d_model=256, n_heads=2, n_kv_heads=1, d_ff=64,
+    )
+    mesh, binding = make_train_mesh(1)
+    setup = setup_train_step(
+        cfg, "1f1b", mesh, binding=binding, microbatch=1, seq_len=512, m=2,
+        tcfg=TrainStepConfig(executor_mode="specialized"),
+    )
+    names = OP_NAME.findall(setup.lower().compile().as_text())
+    flash = [n for n in names if "/flash/" in n]
+    paths = set(_scope_paths(flash))
+    assert paths == {("F", "attn"), ("B", "attn")}, sorted(paths)
+    for name in flash:
+        parts = [_unwrap(p) for p in name.split("/")]
+        assert parts[parts.index("attn") + 1] == "flash", name
+    # the forward kernel in F, its backward kernels (dq; dk, dv) in B
+    for outer, kernel in (("F", "fwd"), ("B", "dq"), ("B", "dkv")):
+        assert any(
+            f"/{outer}/" in n and f"/flash/flash_{kernel}/" in n for n in flash
+        ), (outer, kernel)
+
+
 @pytest.mark.parametrize("schedule,mode", [("zb-v", "scan"), ("1f1b", "specialized")])
 def test_pipe_permutes_are_under_send(schedule, mode):
     env = dict(os.environ)

@@ -467,3 +467,41 @@ def test_residuals_not_needed_after_b():
         jax.tree_util.tree_leaves(grads), jax.tree_util.tree_leaves(grads2)
     ):
         np.testing.assert_array_equal(a, b_)
+
+
+def test_split_parity_through_the_attention_kernel(flash_on_cpu):
+    """The q/k/v/o projections around the blocked attention kernel (its
+    custom VJP brings Pallas backward kernels): B and W from the split
+    reproduce ``jax.vjp``'s dx and weight gradients, and every kernel call
+    of the backward lies in the B slice -- attention has no weights, so W
+    holds only the projections' weight GEMMs."""
+    lcfg = dict(d_model=256, n_heads=2, n_kv_heads=1, head_dim=128, d_ff=32,
+                n_layers=2, tp_size=1)
+    ctx = ShardCtx()
+    params = init_layer("attn", jax.random.PRNGKey(0), lcfg, ctx, jnp.float32)
+    s = 512
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, s, 256)) * 0.5
+    side = {"positions": jnp.arange(s)}
+
+    def f(p, xx, sd):
+        return apply_layer("attn", p, xx, sd["positions"], lcfg, ctx)
+
+    mod = auto_fbw(f, name="attn_flash")
+    y, res = mod.fwd(params, x, side)
+    dy = jax.random.normal(jax.random.PRNGKey(2), y.shape) * 0.5
+    dx, wctx = mod.bwd_x(params, res, dy, side)
+    grads = mod.bwd_w(params, wctx, side)
+    ref_grads, ref_dx = jax.vjp(lambda p, xx: f(p, xx, side), params, x)[1](dy)
+    tol = TOL["float32"]
+    np.testing.assert_allclose(dx, ref_dx, **tol)
+    for k in params:
+        np.testing.assert_allclose(grads[k], ref_grads[k], err_msg=k, **tol)
+
+    # every equation that holds a backward kernel is in B, none in W
+    plan = mod._split
+    kernels = [
+        i for i, e in enumerate(plan.jaxpr.eqns) if "pallas_call" in str(e)
+    ]
+    assert kernels
+    assert set(kernels) <= set(plan.b_eqns)
+    assert not set(kernels) & set(plan.w_eqns)
