@@ -1,9 +1,12 @@
 """A cell of BENCHMARK.json and the data files it names.
 
-Everything that belongs to one configuration, one traffic mix or one cell
-lives in a file of its own, found by name:
+Everything that belongs to one configuration, one architecture, one
+traffic mix or one cell lives in a file of its own, found by name:
 
   configs/<config>.json   the configuration as it is run (the cell's ``file``)
+  archs/<architecture>.py the block of the configuration's ``architecture``:
+                          ``arch_config``, ``layer_leaves``, ``layer``
+                          (the reference's) and ``train_flops_per_token``
   traffic/<traffic>.json  the job: layout, schedule, batch, tokens, optimizer
   limits/<cell>.json      the limit of each number the correctness check compares
   metrics/<metric>.py     the reader of one per-layer metric
@@ -12,8 +15,10 @@ lives in a file of its own, found by name:
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import json
 import pathlib
+from types import ModuleType
 from typing import Any, Dict, List, Optional
 
 HERE = pathlib.Path(__file__).resolve().parent
@@ -25,6 +30,7 @@ class Cell:
     name: str
     chips: int
     config: Dict[str, Any]
+    arch: ModuleType
     traffic: Dict[str, Any]
     limits: Dict[str, Optional[float]]
     end_to_end: List[Dict[str, Any]]
@@ -45,39 +51,41 @@ def _applies(metric: Dict[str, Any], cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def architecture(config: Dict[str, Any], root: pathlib.Path = ROOT) -> ModuleType:
+    """The module of the configuration's ``architecture``, from ``archs/``."""
+    archs = root / HERE.relative_to(ROOT) / "archs"
+    name = config.get("architecture")
+    path = archs / f"{name}.py"
+    if not isinstance(name, str) or not path.is_file():
+        known = sorted(p.stem for p in archs.glob("*.py"))
+        raise ValueError(
+            f"configuration {config.get('name')!r} names architecture {name!r}, "
+            f"which is no module of {archs}: {known}"
+        )
+    spec = importlib.util.spec_from_file_location(f"bench_arch_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load(name: str, root: pathlib.Path = ROOT) -> Cell:
-    """The cell ``name`` with its configuration, traffic, limits and metrics."""
+    """The cell ``name`` with its configuration, architecture, traffic,
+    limits and metrics, all read under the checkout ``root``."""
     bench = _read(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json: {sorted(cells)}")
     w = cells[name]
+    here = root / HERE.relative_to(ROOT)
     cfg_entry = {c["name"]: c for c in bench["configs"]}[w["config"]]
+    config = _read(root / cfg_entry["file"])
     return Cell(
         name=name,
         chips=int(w["chips"]),
-        config=_read(root / cfg_entry["file"]),
-        traffic=_read(HERE / "traffic" / f"{w['traffic']}.json"),
-        limits=_read(HERE / "limits" / f"{name}.json"),
+        config=config,
+        arch=architecture(config, root),
+        traffic=_read(here / "traffic" / f"{w['traffic']}.json"),
+        limits=_read(here / "limits" / f"{name}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
-    )
-
-
-def arch_config(config: Dict[str, Any]):
-    """The program's ArchConfig for a configuration file."""
-    from repro.models.lm import ArchConfig
-
-    return ArchConfig(
-        name=config["name"],
-        family="dense",
-        n_layers=config["num_hidden_layers"],
-        d_model=config["hidden_size"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        d_ff=config["intermediate_size"],
-        vocab=config["vocab_size"],
-        block_pattern=(("attn", "mlp"),),
-        dtype=config["torch_dtype"],
-        source=config["source"],
     )

@@ -1,31 +1,25 @@
 """Model FLOPs of one training token: what the forward and backward passes
-require, with nothing recomputed counted twice.
+require, with nothing recomputed counted twice.  An architecture's module
+(``archs/<architecture>.py``) counts its own layers with these, its
+``train_flops_per_token`` is what ``mfu`` divides by, and an architecture
+that routes counts only the weights a token is routed through.
 
-Dense arithmetic as in ``benchmarks/roofline.py``'s ``active_params``:
-per layer, attention q and o (h x hq*dh each), k and v (h x hk*dh each) and a
-SwiGLU MLP of three h x f matrices; plus the LM head (h x V).  The embedding
-is a lookup and costs no FLOPs.  Each weight costs 2 FLOPs per token forward
-and 4 backward (input and weight gradients): 6 N.  Causal attention adds, per
-layer and token, 2 s hq dh forward (half of QK^T and of PV) and twice that
-backward: 6 s hq dh.
+Each weight a token multiplies costs 2 FLOPs forward and 4 backward (input
+and weight gradients): ``TRAIN_PER_PARAM`` = 6.  The embedding is a lookup
+and costs none; the LM head is h x V.
 """
 
 from typing import Any, Dict
 
-
-def matmul_params(config: Dict[str, Any]) -> int:
-    h = config["hidden_size"]
-    hq = config["num_attention_heads"]
-    hk = config["num_key_value_heads"]
-    dh = config.get("head_dim") or h // hq
-    f = config["intermediate_size"]
-    per_layer = 2 * h * hq * dh + 2 * h * hk * dh + 3 * h * f
-    return config["num_hidden_layers"] * per_layer + h * config["vocab_size"]
+TRAIN_PER_PARAM = 6
 
 
-def train_flops_per_token(config: Dict[str, Any], seq_len: int) -> float:
-    h = config["hidden_size"]
-    hq = config["num_attention_heads"]
-    dh = config.get("head_dim") or h // hq
-    attn = 6 * config["num_hidden_layers"] * seq_len * hq * dh
-    return float(6 * matmul_params(config) + attn)
+def head_params(config: Dict[str, Any]) -> int:
+    return config["hidden_size"] * config["vocab_size"]
+
+
+def causal_attention(seq_len: int, heads: int, qk_dim: int, v_dim: int) -> int:
+    """Training FLOPs of causal attention per token and layer: forward half
+    of QK^T (s x qk_dim a head) and of PV (s x v_dim a head), backward twice
+    the forward."""
+    return 3 * seq_len * heads * (qk_dim + v_dim)

@@ -28,7 +28,7 @@ import numpy as np
 import compare
 import peaks
 import trace_reduce
-from cell import HERE, Cell, arch_config
+from cell import HERE, Cell
 from reference import Reference
 from traffic import TokenBatches, seed_words
 from weights import ProgramLayout
@@ -46,6 +46,7 @@ class RunInfo:
     tokens_per_step: int
     peak: Optional[dict]
     trace: Optional[trace_reduce.Trace]
+    arch: Any = None  # the configuration's archs/<architecture> module
 
 
 class CompileCounter:
@@ -77,7 +78,7 @@ class Bench:
         self.devices = list(devices[: cell.chips])
         mesh, binding = make_train_mesh(t["pipe"], devices=self.devices)
         setup = setup_train_step(
-            arch_config(cell.config),
+            cell.arch.arch_config(cell.config),
             t["schedule"],
             mesh,
             binding=binding,
@@ -91,10 +92,10 @@ class Bench:
         self.setup = setup
         self.reseed(seed)
         self.step = self.setup.lower().compile()
-        self.layout = ProgramLayout(cell.config, self.setup)
-        self._init = self.layout.init_fn(cell.config)
+        self.layout = ProgramLayout(cell.arch, cell.config, self.setup)
+        self._init = self.layout.init_fn()
         self._moments = self.layout.moment_norms_fn()
-        self._change = self.layout.change_norms_fn(cell.config)
+        self._change = self.layout.change_norms_fn()
 
     def reseed(self, seed: int) -> None:
         """Weights and batches from ``seed``; the compiled step stays."""
@@ -152,7 +153,7 @@ class Bench:
         ]
 
     def reference(self, quant=None) -> Dict[str, Any]:
-        ref = Reference(self.cell.config, self.cell.traffic["optimizer"], quant=quant)
+        ref = Reference(self.cell.arch, self.cell.config, self.cell.traffic["optimizer"], quant=quant)
         return ref.run(self.key_words, self.reference_batches(), self.devices)
 
 
@@ -252,6 +253,7 @@ def run_cell(
             tokens_per_step=cell.tokens_per_step,
             peak=peaks.peak(dev.device_kind),
             trace=tr,
+            arch=cell.arch,
         )
         out["metrics"] = {}
         for m in cell.per_layer:

@@ -2,7 +2,11 @@
 
 Straight ``jax.numpy``, under ``jax.default_matmul_precision("highest")``,
 written from the architecture's description and the configuration file; it
-imports nothing of the program.  One step is the mean next-token cross
+imports nothing of the program.  The embedding, the final norm, the head
+and the loss, the optimizer and the run are the same for every
+architecture; a layer is its architecture's (``archs/<architecture>.py``
+``layer(ref, w, x, pos)``), built from this class's primitives: ``mm``,
+``rmsnorm``, ``rope`` and ``attend``.  One step is the mean next-token cross
 entropy over every row of the batch, its gradient, a global-norm clip and
 AdamW with weight decay on every tensor.  Memory is kept in bounds by
 recomputing each layer in the backward pass, attending in blocks of queries
@@ -15,6 +19,7 @@ scale, the precision below the configuration's bfloat16.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional
 
@@ -23,9 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
-from weights import dims, plain_shapes, plain_weights
+from weights import layer_rows, plain_shapes, plain_weights
 
-LAYER_NAMES = ("attn_norm", "wq", "wk", "wv", "wo", "mlp_norm", "w_up", "w_gate", "w_down")
 Q_BLOCK = 1024  # queries attended at a time
 ROW_BLOCK = 1024  # rows of the head and the loss at a time
 E4M3_MAX = 240.0  # largest finite e4m3 value of reduce_precision(4, 3)
@@ -39,11 +43,12 @@ def _fp8(x):
 
 
 class Reference:
-    def __init__(self, config: Dict[str, Any], optimizer: Dict[str, float], quant: Optional[str] = None):
+    def __init__(self, arch, config: Dict[str, Any], optimizer: Dict[str, float], quant: Optional[str] = None):
         if quant not in (None, "fp8"):
             raise ValueError(f"unknown quant {quant!r}")
+        self.arch = arch
         self.config = config
-        self.d = dims(config)
+        self.rows = layer_rows(arch, config)  # layer leaf -> {layer: row}
         self.eps = float(config["rms_norm_eps"])
         self.theta = float(config["rope_theta"])
         self.opt = optimizer
@@ -67,8 +72,10 @@ class Reference:
         return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
 
     def attend(self, q, k, v):
-        """Causal softmax attention, one block of queries at a time."""
+        """Causal softmax attention, one block of queries at a time; the
+        values' head dim may differ from the queries' and keys'."""
         b, s, hq, dh = q.shape
+        dv = v.shape[-1]
         blk = min(Q_BLOCK, s)
         nb = s // blk
         qb = q.reshape(b, nb, blk, hq, dh).transpose(1, 0, 2, 3, 4)
@@ -82,33 +89,17 @@ class Reference:
             return self.mm("bhqk,bkhd->bqhd", jax.nn.softmax(z, axis=-1), v)
 
         _, out = jax.lax.scan(lambda c, a: (c, one(*a)), None, (qb, jnp.arange(nb)))
-        return out.transpose(1, 0, 2, 3, 4).reshape(b, s, hq, dh)
-
-    def layer(self, w, x, pos):
-        d = self.d
-        b, s, _ = x.shape
-        xin = self.rmsnorm(x, w["attn_norm"])
-        q = self.mm("bsh,hd->bsd", xin, w["wq"]).reshape(b, s, d["hq"], d["dh"])
-        k = self.mm("bsh,hd->bsd", xin, w["wk"]).reshape(b, s, d["hk"], d["dh"])
-        v = self.mm("bsh,hd->bsd", xin, w["wv"]).reshape(b, s, d["hk"], d["dh"])
-        q, k = self.rope(q, pos), self.rope(k, pos)
-        rep = d["hq"] // d["hk"]  # query head i reads key/value head i // rep
-        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-        o = self.attend(q, k, v).reshape(b, s, d["hq"] * d["dh"])
-        x = x + self.mm("bsd,dh->bsh", o, w["wo"])
-        xin = self.rmsnorm(x, w["mlp_norm"])
-        up = self.mm("bsh,hf->bsf", xin, w["w_up"])
-        gate = jax.nn.silu(self.mm("bsh,hf->bsf", xin, w["w_gate"]))
-        return x + self.mm("bsf,fh->bsh", up * gate, w["w_down"])
+        return out.transpose(1, 0, 2, 3, 4).reshape(b, s, hq, dv)
 
     def loss_sum(self, params, tokens, labels):
         """Sum of the cross entropy over the rows of one microbatch."""
         b, s = tokens.shape
         pos = jnp.arange(s)
         x = params["embed"][tokens]
-        for li in range(self.d["L"]):
-            w = {n: params[n][li] for n in LAYER_NAMES}
-            x = jax.checkpoint(self.layer)(w, x, pos)
+        layer = functools.partial(self.arch.layer, self)
+        for li in range(self.config["num_hidden_layers"]):
+            w = {n: params[n][self.rows[n][li]] for n in self.arch.layer_leaves(self.config, li)}
+            x = jax.checkpoint(layer)(w, x, pos)
         y = self.rmsnorm(x, params["final_norm"]).reshape(b * s, -1)
         lab = labels.reshape(b * s)
         blk = min(ROW_BLOCK, b * s)
@@ -157,7 +148,7 @@ class Reference:
         new_p, new_m, new_v, leaf_norms = {}, {}, {}, {}
         for name in params:
             gi = g[name] * scale
-            leaf_norms[name] = _leaf_norm(gi, name)
+            leaf_norms[name] = _leaf_norm(gi, name in self.rows)
             mi = b1 * m[name] + (1 - b1) * gi
             vi = b2 * v[name] + (1 - b2) * gi * gi
             mhat, vhat = mi / (1 - b1**t), vi / (1 - b2**t)
@@ -179,14 +170,14 @@ class Reference:
         mesh = jax.make_mesh(
             (len(devices),), ("r",), devices=devices, axis_types=(AxisType.Auto,)
         )
-        shapes = plain_shapes(self.config)
+        shapes = plain_shapes(self.arch, self.config)
         shard = {k: NamedSharding(mesh, _split_spec(s, len(devices))) for k, s in shapes.items()}
         rep = NamedSharding(mesh, P())
-        cfg = self.config
+        arch, cfg = self.arch, self.config
 
         init = jax.jit(
             lambda kw: jax.tree_util.tree_map(
-                lambda a: a.astype(jnp.float32), plain_weights(kw, cfg)
+                lambda a: a.astype(jnp.float32), plain_weights(arch, kw, cfg)
             ),
             out_shardings=shard,
         )
@@ -213,7 +204,7 @@ class Reference:
                 g1 = jax.tree_util.tree_map(np.asarray, leaf)
         del m, v
         change = jax.jit(
-            lambda p, kw: {k: _leaf_norm(p[k] - p0, k) for k, p0 in init(kw).items()},
+            lambda p, kw: {k: _leaf_norm(p[k] - p0, k in self.rows) for k, p0 in init(kw).items()},
             out_shardings=rep,
         )(params, kw)
         return {
@@ -224,8 +215,8 @@ class Reference:
         }
 
 
-def _leaf_norm(x, name):
-    if name in LAYER_NAMES:  # one norm per layer
+def _leaf_norm(x, per_layer: bool):
+    if per_layer:  # one norm a layer
         return jnp.sqrt(jnp.sum(x * x, axis=tuple(range(1, x.ndim))))
     return jnp.sqrt(jnp.sum(x * x))
 

@@ -170,7 +170,7 @@ def _take(run, log) -> Optional[Scoped]:
     import cell
     import harness
 
-    c = cell.Cell("scoped", run.chips, run.config, run.traffic, {}, [], [])
+    c = cell.Cell("scoped", run.chips, run.config, run.arch, run.traffic, {}, [], [])
     bench = harness.Bench(c, jax.devices(), SEED)
     text = bench.step.as_text()
     if not any(hlo_scopes(text)[1].values()):

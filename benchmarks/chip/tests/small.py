@@ -25,6 +25,7 @@ def small_cell(pipe: int, limits=None) -> cell.Cell:
     config = dict(
         name=f"small-p{pipe}",
         source="test",
+        architecture="internlm2",
         hidden_size=64,
         intermediate_size=128,
         num_attention_heads=4,
@@ -48,4 +49,6 @@ def small_cell(pipe: int, limits=None) -> cell.Cell:
         check_steps=3,
     )
     limits = SMALL_LIMITS[pipe] if limits is None else limits
-    return cell.Cell(f"small_p{pipe}", pipe, config, traffic, limits, END_TO_END, [])
+    return cell.Cell(
+        f"small_p{pipe}", pipe, config, cell.architecture(config), traffic, limits, END_TO_END, []
+    )

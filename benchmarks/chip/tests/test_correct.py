@@ -71,7 +71,8 @@ def test_fp8_control_is_not_correct(pipe):
 
 def test_traced_run_reads_its_per_layer_metrics(monkeypatch):
     """The --trace 1 path end to end on the CPU: the CPU has no device
-    planes, so only the host-span and host-clock readers find something."""
+    planes, so only the host-span and host-clock readers find something;
+    a scoped reader (``f_ms``) takes its own profile and reads nothing."""
     import peaks
 
     monkeypatch.setattr(peaks, "peak", lambda kind: peaks.PEAKS["TPU v5 lite"])
@@ -80,6 +81,7 @@ def test_traced_run_reads_its_per_layer_metrics(monkeypatch):
         {"name": "host_data_ms", "unit": "ms"},
         {"name": "mfu", "unit": "%"},
         {"name": "device_idle_share", "unit": "%"},
+        {"name": "f_ms", "unit": "ms"},
     ]
     c = dataclasses.replace(c, per_layer=per_layer)
     out = harness.run_cell(c, 3, 0.5, True, jax.devices(), time.perf_counter(), log=lambda s: None)

@@ -34,13 +34,13 @@ def test_state_reads_back_per_layer():
     initial weights are the plain ones, layer by layer."""
     import numpy as np
 
-    from weights import LAYER_LEAVES, plain_weights
+    from weights import plain_weights
 
     b = harness.Bench(small_cell(4), jax.devices(), 9)
     state = b.init_state()
     got = jax.jit(b.layout.leaf_norms)(state["params"], state["shared"])
-    for name, x in plain_weights(jax.numpy.asarray(b.key_words), b.cell.config).items():
+    for name, x in plain_weights(b.cell.arch, jax.numpy.asarray(b.key_words), b.cell.config).items():
         x = np.asarray(x, np.float64)
-        axes = tuple(range(1, x.ndim)) if name in LAYER_LEAVES else None
+        axes = tuple(range(1, x.ndim)) if name in b.layout.rows else None
         want = np.sqrt(np.sum(x * x, axis=axes))
         np.testing.assert_allclose(np.asarray(got[name]), want, rtol=1e-5)

@@ -1,4 +1,5 @@
-"""The peaks table and the model-FLOP function against hand counts."""
+"""The peaks table and the architectures' model-FLOP functions against
+hand counts."""
 
 import json
 
@@ -11,6 +12,10 @@ import peaks
 
 def _config(name):
     return json.load(open(cell.HERE / "configs" / f"{name}.json"))
+
+
+def _arch(name):
+    return cell.architecture(_config(name))
 
 
 def test_v5e_peaks_and_unknown_kind():
@@ -35,12 +40,14 @@ def test_v5e_peaks_and_unknown_kind():
     ],
 )
 def test_train_flops_per_token_by_hand(name, seq, per_layer, head, attn):
-    c = _config(name)
+    c, arch = _config(name), _arch(name)
     layers = c["num_hidden_layers"]
-    assert flops.matmul_params(c) == layers * per_layer + head
-    assert flops.train_flops_per_token(c, seq) == 6 * (layers * per_layer + head) + layers * attn
+    assert arch.matmul_params(c) == layers * per_layer + head
+    assert arch.train_flops_per_token(c, seq) == 6 * (layers * per_layer + head) + layers * attn
+    assert flops.causal_attention(seq, 16, 128, 128) == attn
 
 
 def test_internlm2_one_chip_flops_value():
     # 62.9M a layer, 189.5M in the head, attention at seq 4096: 1.99 GFLOP
-    assert flops.train_flops_per_token(_config("internlm2-1.8b-2l"), 4096) == 1992818688
+    c = _config("internlm2-1.8b-2l")
+    assert _arch("internlm2-1.8b-2l").train_flops_per_token(c, 4096) == 1992818688
