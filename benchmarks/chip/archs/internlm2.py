@@ -12,7 +12,7 @@ block, so every leaf is at every layer.
 
 from __future__ import annotations
 
-from typing import Any, Dict
+from typing import Any, Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -98,7 +98,13 @@ def matmul_params(config: Dict[str, Any]) -> int:
     return config["num_hidden_layers"] * per_layer + flops.head_params(config)
 
 
-def train_flops_per_token(config: Dict[str, Any], seq_len: int) -> float:
+def attention_shapes(config: Dict[str, Any]) -> List[Tuple[int, int, int, int]]:
+    """(query heads, key/value heads, qk head dim, v head dim) of each
+    layer's causal attention."""
     d = dims(config)
-    attn = config["num_hidden_layers"] * flops.causal_attention(seq_len, d["hq"], d["dh"], d["dh"])
+    return [(d["hq"], d["hk"], d["dh"], d["dh"])] * config["num_hidden_layers"]
+
+
+def train_flops_per_token(config: Dict[str, Any], seq_len: int) -> float:
+    attn = sum(flops.causal_attention(seq_len, hq, qk, v) for hq, _, qk, v in attention_shapes(config))
     return float(flops.TRAIN_PER_PARAM * matmul_params(config) + attn)

@@ -6,10 +6,13 @@ traffic mix or one cell lives in a file of its own, found by name:
   configs/<config>.json   the configuration as it is run (the cell's ``file``)
   archs/<architecture>.py the block of the configuration's ``architecture``:
                           ``arch_config``, ``layer_leaves``, ``layer``
-                          (the reference's) and ``train_flops_per_token``
+                          (the reference's), ``train_flops_per_token`` and
+                          ``attention_shapes``
   traffic/<traffic>.json  the job: layout, schedule, batch, tokens, optimizer
   limits/<cell>.json      the limit of each number the correctness check compares
-  metrics/<metric>.py     the reader of one per-layer metric
+  metrics/<metric>.py     the reader of one per-layer metric, ``read(run)``;
+                          it may state ``SCOPES``, the program scopes it reads
+                          beyond ``scoped.PROGRAM_SCOPES``
 """
 
 from __future__ import annotations
@@ -51,6 +54,19 @@ def _applies(metric: Dict[str, Any], cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def load_module(path: pathlib.Path, name: str) -> ModuleType:
+    """The Python file at ``path``, loaded as a module called ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def metrics_dir(root: pathlib.Path = ROOT) -> pathlib.Path:
+    """``metrics/`` under the checkout ``root``: one reader module a metric."""
+    return root / HERE.relative_to(ROOT) / "metrics"
+
+
 def architecture(config: Dict[str, Any], root: pathlib.Path = ROOT) -> ModuleType:
     """The module of the configuration's ``architecture``, from ``archs/``."""
     archs = root / HERE.relative_to(ROOT) / "archs"
@@ -62,10 +78,7 @@ def architecture(config: Dict[str, Any], root: pathlib.Path = ROOT) -> ModuleTyp
             f"configuration {config.get('name')!r} names architecture {name!r}, "
             f"which is no module of {archs}: {known}"
         )
-    spec = importlib.util.spec_from_file_location(f"bench_arch_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load_module(path, f"bench_arch_{name}")
 
 
 def load(name: str, root: pathlib.Path = ROOT) -> Cell:

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import importlib.util
+import pathlib
 import shutil
 import sys
 import tempfile
@@ -28,7 +28,7 @@ import numpy as np
 import compare
 import peaks
 import trace_reduce
-from cell import HERE, Cell
+from cell import ROOT, Cell, load_module, metrics_dir
 from reference import Reference
 from traffic import TokenBatches, seed_words
 from weights import ProgramLayout
@@ -163,12 +163,10 @@ def free(tree) -> None:
             leaf.delete()
 
 
-def load_reader(name: str):
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+def load_reader(name: str, root: pathlib.Path = ROOT):
+    """``read`` of the per-layer metric ``name``, from ``metrics/`` under the
+    checkout ``root``."""
+    return load_module(metrics_dir(root) / f"{name}.py", f"bench_metric_{name}").read
 
 
 def run_cell(

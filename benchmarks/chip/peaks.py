@@ -20,3 +20,9 @@ def peak(device_kind: str) -> dict:
         return PEAKS[device_kind]
     except KeyError:
         raise KeyError(f"no published peak for device kind {device_kind!r}") from None
+
+
+def roofline_s(peak: dict, flops: float, nbytes: float) -> float:
+    """The least time the chip could take for ``flops`` bf16 operations that
+    move ``nbytes`` through HBM: the larger of the two over their peaks."""
+    return max(flops / peak["bf16_flops_per_s"], nbytes / peak["hbm_bytes_per_s"])

@@ -6,7 +6,13 @@ nothing: ``F``, ``B`` and ``W`` (the executor's passes), ``head_ce`` (the
 LM head and its loss, inside ``F``, ``B`` and ``W``), ``attn`` (q, k, v to
 the attention output), ``send`` (the pipe's collective permutes in the
 tick), ``opt`` (the optimizer tail) and ``sync`` (the reductions over the
-pipe after the last tick and in post-validation, inside ``opt``).  Its
+pipe after the last tick and in post-validation, inside ``opt``): these
+are ``PROGRAM_SCOPES``.  A reader module ``metrics/<metric>.py`` may state
+further scopes that it reads, ``SCOPES = ("flash",)``: ``known_scopes``
+adds each, once per process, so a scope the program gains is read by a new
+metric file alone.  A scope path keeps the known scopes among an op's
+``op_name`` components, outermost first (``B/attn/flash``); any other
+component (``jit(body)``, ``shard_map``, ``while``) is dropped.  Its
 feed writes host spans on the device ops' clock
 (``jax.profiler.TraceAnnotation``): ``repro.feed`` around
 ``repro.feed.batch``, ``repro.feed.build`` and ``repro.feed.put``.
@@ -28,16 +34,19 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import functools
+import pathlib
 import re
 import shutil
 import sys
 import tempfile
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import cell
 import trace_reduce
 from trace_reduce import DEVICE_PLANE, OPS_LINE, Event, busy_intervals, self_times
 
-SCOPES = ("F", "B", "W", "opt", "head_ce", "attn", "send", "sync")
+PROGRAM_SCOPES = ("F", "B", "W", "opt", "head_ce", "attn", "send", "sync")
 WAITS = ("send", "sync")  # scopes of the collectives a stage waits in
 SPAN_PREFIXES = ("bench.", "repro.")
 MODULES_LINE = "XLA Modules"
@@ -67,33 +76,56 @@ class Scoped:
 # scopes from the HLO text
 # --------------------------------------------------------------------- #
 _WRAPPED = re.compile(r"\w+\((.*)\)")  # jvp(attn), transpose(jvp(attn))
-_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*?metadata=\{[^}]*?op_name=\"([^\"]*)\"", re.M)
+# an instruction starts a line; its attributes may run over several lines, as
+# a Pallas call's kernel_metadata does, up to the next instruction
+_INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ", re.M)
+_OP_NAME = re.compile(r"metadata=\{[^}]*?op_name=\"([^\"]*)\"")
 _MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
 
 
-def scope_path(op_name: str) -> str:
-    """The scopes among the components of an ``op_name``, outermost first,
-    each taken out of the ``jvp(...)`` / ``transpose(...)`` that
-    differentiation wraps it in: ``jit(body)/.../B/transpose(jvp(attn))/dot``
-    -> ``B/attn``."""
+@functools.lru_cache(maxsize=None)
+def known_scopes(root: pathlib.Path = cell.ROOT) -> Tuple[str, ...]:
+    """``PROGRAM_SCOPES``, then each scope that a reader in ``metrics/``
+    under the checkout ``root`` states as its ``SCOPES``, by file name."""
+    out = list(PROGRAM_SCOPES)
+    for path in sorted(cell.metrics_dir(root).glob("*.py")):
+        stated = getattr(cell.load_module(path, f"bench_metric_{path.stem}"), "SCOPES", ())
+        names = not isinstance(stated, str) and all(isinstance(sc, str) and sc and "/" not in sc for sc in stated)
+        if not names:
+            raise ValueError(f"{path}: SCOPES is a tuple of scope names, not {stated!r}")
+        out += [sc for sc in stated if sc not in out]
+    return tuple(out)
+
+
+def scope_path(op_name: str, root: pathlib.Path = cell.ROOT) -> str:
+    """The known scopes (of the checkout ``root``) among the components of
+    an ``op_name``, outermost first, each taken out of the ``jvp(...)`` /
+    ``transpose(...)`` that differentiation wraps it in:
+    ``jit(body)/.../B/transpose(jvp(attn))/dot`` -> ``B/attn``."""
+    scopes = known_scopes(root)
     out = []
     for part in op_name.split("/"):
         m = _WRAPPED.fullmatch(part)
         while m:
             part = m.group(1)
             m = _WRAPPED.fullmatch(part)
-        if part in SCOPES:
+        if part in scopes:
             out.append(part)
     return "/".join(out)
 
 
-def hlo_scopes(hlo_text: str) -> Tuple[str, Dict[str, str]]:
-    """(module name, instruction -> scope path) of a compiled HLO module."""
+def hlo_scopes(hlo_text: str, root: pathlib.Path = cell.ROOT) -> Tuple[str, Dict[str, str]]:
+    """(module name, instruction -> scope path) of a compiled HLO module,
+    for each instruction with an ``op_name``."""
     module = _MODULE.search(hlo_text)
-    return (
-        module.group(1) if module else "",
-        {name: scope_path(op) for name, op in _INSTR.findall(hlo_text)},
-    )
+    starts = list(_INSTR.finditer(hlo_text))
+    ends = [m.start() for m in starts[1:]] + [len(hlo_text)]
+    paths = {}
+    for m, end in zip(starts, ends):
+        op = _OP_NAME.search(hlo_text, m.end(), end)
+        if op:
+            paths[m.group(1)] = scope_path(op.group(1), root)
+    return (module.group(1) if module else "", paths)
 
 
 def in_scope(path: str, scope: str) -> bool:
@@ -167,7 +199,6 @@ def profile(run, log=lambda s: print(s, file=sys.stderr, flush=True)) -> Optiona
 def _take(run, log) -> Optional[Scoped]:
     import jax
 
-    import cell
     import harness
 
     c = cell.Cell("scoped", run.chips, run.config, run.arch, run.traffic, {}, [], [])
@@ -290,17 +321,28 @@ def feed_wait_ms(s: Scoped) -> Optional[float]:
     return 1e3 * sum(per.values()) / len(per) / s.steps
 
 
-def unscoped(s: Scoped, plane: str, n: int = 3) -> Tuple[float, List[List]]:
-    """(share of the chip's busy time in step ops under no scope, the ``n``
-    of them with the most self time in ms per step)."""
+def _bare(s: Scoped, plane: str) -> Tuple[Dict[str, float], float]:
+    """(self ns in the window of each step op under no known scope, self ns
+    of every op) on one chip."""
     t0, t1 = window(s)
     own = self_times([(name, t, d) for name, _, t, d in s.ops[plane]], t0, t1)
     step = {name for name, m, _, _ in s.ops[plane] if m == s.step_module}
-    bare = {k: v for k, v in own.items() if k in step and not s.scopes.get(k)}
-    total = sum(own.values())
+    return {k: v for k, v in own.items() if k in step and not s.scopes.get(k)}, sum(own.values())
+
+
+def unscoped(s: Scoped, plane: str, n: int = 3) -> Tuple[float, List[List]]:
+    """(share of the chip's busy time in step ops under no scope, the ``n``
+    of them with the most self time in ms per step)."""
+    bare, total = _bare(s, plane)
     top = sorted(bare.items(), key=lambda kv: -kv[1])[:n]
     share = sum(bare.values()) / total if total else 0.0
     return share, [[k, v / 1e6 / s.steps] for k, v in top]
+
+
+def unscoped_ms(s: Scoped) -> float:
+    """Self time of the step's ops under no known scope, ms per step, mean
+    over chips: the time ``unscoped`` gives as a share."""
+    return sum(sum(_bare(s, plane)[0].values()) for plane in s.ops) / 1e6 / len(s.ops) / s.steps
 
 
 def named_gaps(s: Scoped, n: int = 5) -> List[List]:
@@ -326,11 +368,11 @@ def report(s: Scoped) -> List[str]:
     """The log lines of a profile: per chip, ms per step of each scope, the
     stage's idle share, the share of busy time under no scope and its three
     largest ops; then the longest idle gaps by span."""
-    per = {sc: scope_seconds(s, sc) or {} for sc in SCOPES}
+    per = {sc: scope_seconds(s, sc) or {} for sc in known_scopes()}
     idle = stage_idle(s)
     lines = [f"scopes: {s.steps} profiled steps, ms per step by chip"]
     for plane in s.ops:
-        cells = " ".join(f"{sc} {1e3 * per[sc].get(plane, 0.0) / s.steps:.3f}" for sc in SCOPES)
+        cells = " ".join(f"{sc} {1e3 * ms.get(plane, 0.0) / s.steps:.3f}" for sc, ms in per.items())
         share, top = unscoped(s, plane)
         tops = ", ".join(f"{k} {v:.3f}" for k, v in top)
         lines.append(

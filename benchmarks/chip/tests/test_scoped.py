@@ -5,12 +5,15 @@ one; the parent's readers keep their values on the recorded trace."""
 
 import json
 import pathlib
+import shutil
 import time
 
 import jax
 import pytest
 
+import cell
 import harness
+import peaks
 import scoped
 import trace_reduce as tr
 
@@ -59,16 +62,26 @@ def _hand():
 # 10+8, head_ce 5+4+2 / 0, attn 5+6 / 10, send 10 / 30, sync 0 / 8; the
 # eager copy.1 counts in no scope.  Busy less send and sync: 81-10 /
 # 78-38 of 100.  Feed 1..9: chip 0 busy 4..5, chip 1 idle throughout.
+# Unscoped: chip 0 the loop's own 2 ms and fusion.8's 8, chip 1 none.
 EXPECT = {
     "f_ms": 20.0, "b_ms": 10.0, "w_ms": 5.0, "opt_ms": 19.0, "head_ce_ms": 5.5,
     "attn_ms": 10.5, "send_ms": 20.0, "stage_idle_share": 44.5, "feed_wait_ms": 7.5,
+    "unscoped_ms": 5.0,
 }
 
 
-def _read(name, profile, monkeypatch):
+def _read(name, profile, monkeypatch, c=None, root=cell.ROOT):
+    """The reader ``name`` (under the checkout ``root``) on ``profile``, as
+    a traced run of the cell ``c`` would read it."""
     monkeypatch.setattr(scoped, "_take", lambda run, log: profile)
-    run = harness.RunInfo({}, {}, len(profile.ops), profile.steps, 0.1, 1, None, tr.Trace({}, []))
-    return harness.load_reader(name)(run)
+    if c is None:
+        run = harness.RunInfo({}, {}, len(profile.ops), profile.steps, 0.1, 1, None, tr.Trace({}, []))
+    else:
+        run = harness.RunInfo(
+            c.config, c.traffic, c.chips, profile.steps, 0.1, c.tokens_per_step,
+            peaks.PEAKS["TPU v5 lite"], tr.Trace({}, []), c.arch,
+        )
+    return harness.load_reader(name, root)(run)
 
 
 @pytest.mark.parametrize("name", sorted(EXPECT))
@@ -167,8 +180,9 @@ def test_recorded_profile(path, monkeypatch):
     value it gave there, and the passes cover the step's busy time."""
     d = json.load(open(path))
     s = scoped.Scoped.from_json(d)
+    c = cell.load(d["workload"]) if "workload" in d else None
     for name, value in d["expect"].items():
-        got = _read(name, s, monkeypatch)
+        got = _read(name, s, monkeypatch, c)
         assert (got is None and value is None) or got == pytest.approx(value, rel=1e-9), name
     t0, t1 = scoped.window(s)
     busy = [
@@ -178,3 +192,117 @@ def test_recorded_profile(path, monkeypatch):
     busy_ms = sum(busy) / len(busy) / s.steps / 1e6
     passes = sum(d["expect"][k] for k in ("f_ms", "b_ms", "w_ms", "opt_ms"))
     assert passes <= busy_ms * (1 + 1e-9)
+
+
+# --------------------------------------------------------------------- #
+# the open scope set
+# --------------------------------------------------------------------- #
+# One step on one chip, window 0..100 ms.  The Pallas call's attributes run
+# over three lines, as a call given ``metadata=`` prints them.
+PROBE_HLO = "\n".join([
+    "HloModule jit_body, is_scheduled=true",
+    "ENTRY %main {",
+    '  %fusion.1 = bf16[8] fusion(), metadata={op_name="jit(body)/while/body/F/probe/dot_general"}',
+    '  %fusion.2 = bf16[8] fusion(%fusion.1), metadata={op_name="jit(body)/while/body/probe/mul"}',
+    '  %flash_dq.1 = bf16[8] custom-call(%fusion.2), custom_call_target="tpu_custom_call", '
+    "frontend_attributes={kernel_metadata={",
+    '"a":"1"',
+    '}}, metadata={op_name="jit(body)/while/body/B/transpose(jvp(attn))/flash/pallas_call" stack_frame_id=3}',
+    '  ROOT %copy.3 = bf16[8] copy(%flash_dq.1), metadata={op_name="jit(body)/while/body/copy"}',
+    "}",
+])
+PROBE_OPS = [("fusion.1", 10, 10), ("fusion.2", 20, 5), ("flash_dq.1", 30, 8), ("copy.3", 40, 6)]
+PROBE_READER = """import scoped
+
+SCOPES = ("probe",)
+
+
+def read(run):
+    return scoped.reading(run, lambda s: scoped.scope_ms(s, "probe"))
+"""
+
+
+def _probe_profile(root):
+    module, scopes = scoped.hlo_scopes(PROBE_HLO, root)
+    ops = {"/device:TPU:0": [(n, module, t * MS, d * MS) for n, t, d in PROBE_OPS]}
+    return scoped.Scoped(ops, [("bench.step", 0, 100 * MS)], module, scopes, 1)
+
+
+def test_new_scope_is_a_new_metric_file(tmp_path, monkeypatch):
+    """A reader that states a scope outside the program's set reads it from a
+    copy of the benchmark's files with only that file added."""
+    here = tmp_path / cell.HERE.relative_to(cell.ROOT)
+    shutil.copytree(cell.HERE, here, ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    (here / "metrics" / "probe_ms.py").write_text(PROBE_READER)
+    assert "probe" not in scoped.PROGRAM_SCOPES and "probe" not in scoped.known_scopes()
+    assert scoped.known_scopes(tmp_path) == scoped.known_scopes() + ("probe",)
+
+    before, after = _probe_profile(cell.ROOT), _probe_profile(tmp_path)
+    assert before.scopes == {"fusion.1": "F", "fusion.2": "", "flash_dq.1": "B/attn/flash", "copy.3": ""}
+    assert after.scopes == dict(before.scopes, **{"fusion.1": "F/probe", "fusion.2": "probe"})
+    assert scoped.scope_ms(before, "probe") is None
+    assert _read("probe_ms", after, monkeypatch, root=tmp_path) == pytest.approx(15.0)
+    # what the new scope names leaves the unscoped time; the rest reads alike
+    assert _read("unscoped_ms", before, monkeypatch) == pytest.approx(11.0)
+    assert _read("unscoped_ms", after, monkeypatch, root=tmp_path) == pytest.approx(6.0)
+    for s in (before, after):
+        assert [scoped.scope_ms(s, sc) for sc in ("F", "B", "attn", "flash")] == pytest.approx([10.0, 8.0, 8.0, 8.0])
+
+
+@pytest.mark.parametrize("stated", ["probe", ("probe", "a/b"), ("",), (1,)])
+def test_scopes_stated_badly_are_refused(stated, tmp_path):
+    metrics = cell.metrics_dir(tmp_path)
+    metrics.mkdir(parents=True)
+    (metrics / "bad.py").write_text(f"SCOPES = {stated!r}\n")
+    with pytest.raises(ValueError, match="SCOPES"):
+        scoped.known_scopes(tmp_path)
+
+
+@pytest.mark.parametrize("op_name, path", [
+    ("jit(body)/while/body/F/attn/flash/_attend/pallas_call", "F/attn/flash"),
+    ("jit(body)/while/body/B/transpose(jvp(attn))/flash/pallas_call", "B/attn/flash"),
+    ("jit(body)/while/body/B/transpose(jvp(attn))/transpose", "B/attn"),
+    ("jit(body)/shard_map/while/body/closed_call/copy", ""),
+])
+def test_flash_is_a_scope_under_attn(op_name, path):
+    assert "flash" in scoped.known_scopes()  # stated by metrics/attn_roofline.py
+    assert scoped.scope_path(op_name) == path
+    assert scoped.in_scope(path, "attn") == ("attn" in op_name)
+
+
+def test_hlo_scopes_reads_an_instruction_over_several_lines():
+    module, scopes = scoped.hlo_scopes(PROBE_HLO)
+    assert module == "jit_body"
+    assert scopes == {"fusion.1": "F", "fusion.2": "", "flash_dq.1": "B/attn/flash", "copy.3": ""}
+    one_line = PROBE_HLO.replace('{\n"a":"1"\n}}', '{"a":"1"}}')
+    assert one_line.count("\n") == PROBE_HLO.count("\n") - 2
+    assert scoped.hlo_scopes(one_line) == (module, scopes)
+
+
+# --------------------------------------------------------------------- #
+# attention's roofline share
+# --------------------------------------------------------------------- #
+# Per token and layer, 16 query heads and 8 key/value heads of 128, bf16:
+# 3 * seq * 16 * (128 + 128) FLOPs; forward bytes 2 * (q 2048 + k 1024 +
+# v 1024 + o 2048) + lse 4 * 16 = 12352, backward 2 * (q, k, v, o, dO,
+# dq, dk, dv: 12288) + lse 64 = 24640.  A chip's layers see 16384 tokens
+# at seq 4096 (2 layers, one chip), 12288 at seq 1024 (2 of 8 layers a chip).
+ATTN_WORK = {
+    "internlm2_1c_s4096": (3 * 4096 * 16 * 256 * 16384 * 2, (12352 + 24640) * 16384 * 2),
+    "internlm2_4c_zbv_s1024": (3 * 1024 * 16 * 256 * 12288 * 2, (12352 + 24640) * 12288 * 2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ATTN_WORK))
+def test_attn_roofline_against_hand_counts(name, monkeypatch):
+    c = cell.load(name)
+    reader = cell.load_module(cell.metrics_dir() / "attn_roofline.py", "attn_roofline")
+    run = harness.RunInfo(c.config, c.traffic, c.chips, 1, 0.1, c.tokens_per_step, None, None, c.arch)
+    flops, nbytes = ATTN_WORK[name]
+    assert reader.work(run) == (flops, nbytes)
+    # compute bounds both: at seq 4096 1.649 TFLOP, 8.372 ms, against 1.480 ms of HBM
+    roof = flops / 197e12
+    assert roof > nbytes / 819e9
+    # the hand-made profile's attn is 10.5 ms a step, mean over chips
+    assert _read("attn_roofline", _hand(), monkeypatch, c) == pytest.approx(100 * roof / 10.5e-3, rel=1e-12)
+
